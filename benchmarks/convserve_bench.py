@@ -52,13 +52,13 @@ _HW: list = []  # one-shot cache of the calibrated model for this run
 
 def bench_hw() -> analysis.HardwareModel:
     """The calibrated hardware model every bench number is predicted
-    against: the paper-machine constants with compute/memory roofs
-    replaced by the measured GEMM/stream microbenchmark (cached in the
-    wisdom file, so repeat runs pay nothing).  Hardcoded SKYLAKE_X peaks
-    on an arbitrary host made `measured_over_predicted` pure noise
+    against: the device's model (`tune.default_hw`) with compute/memory
+    roofs replaced by the measured GEMM/stream microbenchmark (cached in
+    the wisdom file, so repeat runs pay nothing).  Uncalibrated peaks on
+    an arbitrary host made `measured_over_predicted` pure noise
     (80-440x); calibration is what makes the divergence signal usable."""
     if not _HW:
-        _HW.append(analysis.calibrated_hw(analysis.SKYLAKE_X))
+        _HW.append(analysis.calibrated_hw())
     return _HW[0]
 
 

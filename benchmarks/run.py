@@ -30,6 +30,9 @@ def main() -> None:
         "results (default: BENCH_convserve.json in the cwd)",
     )
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     batch = 1 if (args.quick or args.smoke) else 2
     if args.smoke and args.only is None:
         args.only = "convserve"

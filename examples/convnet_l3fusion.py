@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.convnets import vgg_mixed_channel
 from repro.convserve import (
     ConvServeConfig,
@@ -28,6 +29,7 @@ from repro.convserve import (
 
 
 def main():
+    enable_compile_cache()
     spec = vgg_mixed_channel(c_in=3)
     engine = Engine()  # TPU model on TPU backends, SkylakeX otherwise
     ws = init_weights(spec, seed=0)

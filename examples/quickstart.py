@@ -13,6 +13,10 @@ import sys
 
 sys.path.insert(0, "src")
 
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
+
 import jax.numpy as jnp
 import numpy as np
 

@@ -19,6 +19,7 @@ import sys
 
 sys.path.insert(0, "src")
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.configs.convnets import tiny_testnet  # noqa: E402
 from repro.convserve import Engine, init_weights  # noqa: E402
 from repro.convserve.obs import (  # noqa: E402
@@ -41,6 +42,7 @@ TRACE_PATH = "serve_online.trace.json"
 
 
 def main() -> None:
+    enable_compile_cache()
     spec = tiny_testnet(4)
     weights = init_weights(spec, seed=0)
     engine = Engine()

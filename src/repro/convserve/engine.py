@@ -53,8 +53,13 @@ class CompiledNet:
     hw: Optional[analysis.HardwareModel] = None
     report: Optional[CheckReport] = None
 
-    def __call__(self, x, sizes=None):
-        return self.executor(x, sizes)
+    def __call__(self, x, sizes=None, *, mesh=None):
+        return self.executor(x, sizes, mesh=mesh)
+
+    def lower(self, x, sizes=None, *, mesh=None):
+        """The lowered wave program for this batch (see
+        `NetExecutor.lower`)."""
+        return self.executor.lower(x, sizes, mesh=mesh)
 
     @property
     def cache(self) -> KernelCache:

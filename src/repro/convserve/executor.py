@@ -33,6 +33,7 @@ from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core import registry
 from repro.convserve.cache import KernelCache, weights_fingerprint
@@ -151,7 +152,7 @@ class NetExecutor:
     def compiles_by_bucket(self) -> Dict[int, int]:
         """Compiled-program count per spatial bucket (input H)."""
         out: Dict[int, int] = {}
-        for shape, _ in self._compiled:
+        for shape, *_ in self._compiled:
             out[shape[1]] = out.get(shape[1], 0) + 1
         return out
 
@@ -301,27 +302,60 @@ class NetExecutor:
                 )
         return sizes
 
+    def _program(self, x, sizes, mesh):
+        """The jitted wave program for this batch shape: the whole net
+        on one device, or -- with `mesh` -- one copy per device of the
+        mesh's data axis, each running its rows of the wave (weights
+        and transforms replicated).  Rows are independent, so the
+        per-device program is the single-device one at a smaller batch,
+        and every kernel runs whole on its own device."""
+        key = (tuple(x.shape), sizes is not None, mesh)
+        fn = self._compiled.get(key)
+        if fn is None:
+            fwd = self._forward
+            if mesh is not None:
+                rows = P("data")
+                fwd = jax.shard_map(
+                    fwd, mesh=mesh,
+                    in_specs=(rows, P(), P(), None if sizes is None else rows),
+                    out_specs=rows,
+                    # Pallas outputs carry no varying-axes annotation
+                    check_vma=False,
+                )
+            fn = jax.jit(fwd)
+            self._compiled[key] = fn
+        return fn
+
     def __call__(
-        self, x: jnp.ndarray, sizes: Optional[jnp.ndarray] = None
+        self, x: jnp.ndarray, sizes: Optional[jnp.ndarray] = None,
+        *, mesh=None,
     ) -> jnp.ndarray:
         """Run one batch.
 
         x: (B, H, W, C); defines the bucket.  sizes: optional (B, 2) int32
         true (h, w) per sample for ragged batches -- samples are zeroed
         beyond their true extent stage by stage so padded serving is
-        exact (see module docstring).
+        exact (see module docstring).  mesh: split the batch over the
+        mesh's ``data`` axis, one shard per device (B must divide it).
         """
         x = jnp.asarray(x, self.dtype)
         sizes = self._validate_call(x, sizes)
         wts = self._fetch_transforms()
-        key = (tuple(x.shape), sizes is not None)
-        fn = self._compiled.get(key)
-        if fn is None:
-            fn = jax.jit(self._forward)
-            self._compiled[key] = fn
+        fn = self._program(x, sizes, mesh)
         self.calls += 1
         self.images += int(x.shape[0])
         return fn(x, self.weights, wts, sizes)
+
+    def lower(self, x: jnp.ndarray, sizes: Optional[jnp.ndarray] = None,
+              *, mesh=None):
+        """The wave program for this batch, lowered but not compiled or
+        run: `as_text()` is what the compiler receives."""
+        x = jnp.asarray(x, self.dtype)
+        sizes = self._validate_call(x, sizes)
+        wts = self._fetch_transforms()
+        return self._program(x, sizes, mesh).lower(
+            x, self.weights, wts, sizes
+        )
 
     def profile_stages(
         self, x: jnp.ndarray, sizes: Optional[jnp.ndarray] = None

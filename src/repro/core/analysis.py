@@ -22,6 +22,9 @@ class HardwareModel:
     fast_shared_bw: float  # bytes/s of the shared fast level (L3 / VMEM feed)
     fast_shared_bytes: int  # capacity of that level
     private_bytes: int  # per-core private working memory (L2 / VMEM budget)
+    # the tile-engine backend this device runs: "pallas" plans must fit
+    # the Pallas kernel's VMEM layout (kernels.fused_tile.kernel)
+    tile_backend: str = "xla"
 
     @property
     def cmr_dram(self) -> float:
@@ -52,11 +55,15 @@ MOBILE_I7 = HardwareModel(
     fast_shared_bytes=8 * 2**20,
     private_bytes=256 * 2**10,
 )
-# TPU v5e, the adaptation target.  The "fast shared" level is VMEM; its feed
-# bandwidth is effectively the VREG load rate -- we conservatively model the
-# VMEM->compute CMR as ~2 (VMEM streams near compute rate), which makes the
-# L3-lower-bound on R mild; the binding constraints on TPU are the HBM AI and
-# the VMEM capacity budget.
+# TPU v5e, the adaptation target (`device_kind` "TPU v5 lite").  Peaks
+# are Google Cloud's published v5e figures (197 TFLOP/s bf16, 819 GB/s
+# HBM).  The "fast shared" level is VMEM: the compiler reports 128 MiB
+# per chip, of which one tile-kernel call may claim half
+# (`kernels.fused_tile.kernel.VMEM_BUDGET_BYTES`); the per-task shared
+# buffer gets half of that.  The VMEM feed is modeled at CMR ~2 (VMEM
+# streams near compute rate), which makes the lower bound on R mild;
+# the binding constraints are the HBM AI and the kernel's VMEM budget,
+# which `TransformedAlgorithm.plan` checks layer by layer.
 TPU_V5E = HardwareModel(
     name="TPU v5e (per chip)",
     peak_flops=197e12,
@@ -64,6 +71,7 @@ TPU_V5E = HardwareModel(
     fast_shared_bw=197e12 / 2.0,
     fast_shared_bytes=64 * 2**20,
     private_bytes=32 * 2**20,
+    tile_backend="pallas",
 )
 
 

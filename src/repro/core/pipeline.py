@@ -33,6 +33,7 @@ transform factory, so a concrete algorithm (`l3_fused`, `fft_fused`,
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -360,10 +361,33 @@ class TransformedAlgorithm(registry.Algorithm):
         cost = registry.fused_auto_cost(
             spec, hw, ta, self.r_floor(hw), blocks=blocks
         )
+        if hw.tile_backend == "pallas" and not self._kernel_fits(
+            tr, spec, r, blocks
+        ):
+            cost = math.inf  # the compiled kernel cannot hold this layer
         return registry.AlgoPlan(
             self.name, spec, params,
             predicted_util=util, cost=cost, tuned=tuned,
         )
+
+    @staticmethod
+    def _kernel_fits(tr: transforms.Transform, spec, r: int, blocks) -> bool:
+        """Whether the Pallas tile kernel can run this layer at the blocks
+        it will be launched with (`blocks` from wisdom, else R tiles and
+        one task per program): its channel counts must fill one partial
+        or whole lane tiles, and its blocks (stationary right-hand
+        matrices above all) must fit the kernel's VMEM budget.  Families
+        without a kernel spec run the scan engine and always fit."""
+        from repro.kernels.fused_tile import kernel as _kernel
+
+        ks = tr.kernel_spec()
+        if ks is None:
+            return True
+        if blocks is not None:
+            r, tpp = blocks.r, max(1, blocks.tasks_per_program)
+        else:
+            tpp = 1
+        return _kernel.kernel_fits(ks, spec.c_in, spec.c_out, r, tpp)
 
     def tile_algebra(self, plan: registry.AlgoPlan):
         return self.make_transform(plan.spec, plan.params).algebra

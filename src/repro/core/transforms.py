@@ -35,6 +35,7 @@ import functools
 from fractions import Fraction
 from typing import ClassVar, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -319,6 +320,30 @@ class TileKernelSpec:
             .reshape(s, g, cg2, 2 * cgo)
         )
 
+    def pack_planes(self, wt: jnp.ndarray, groups: int = 1) -> jnp.ndarray:
+        """Family-native transformed kernels -> (planes, s_mix, C, C')
+        dense real mix matrices, the Pallas kernel's layout: one plane
+        for a real family, (re, im) for a complex one.  Grouped kernels
+        become block-diagonal (exact zeros off the group blocks)."""
+        w3 = wt.reshape(self.s_mix, wt.shape[-2], wt.shape[-1])
+        parts = (
+            [w3.astype(jnp.float32)]
+            if self.planes == 1
+            else [jnp.real(w3).astype(jnp.float32),
+                  jnp.imag(w3).astype(jnp.float32)]
+        )
+        if groups > 1:
+            s, cg, c_out = w3.shape
+            eye = jnp.eye(groups, dtype=jnp.float32)
+            parts = [
+                (
+                    w.reshape(s, 1, cg, groups, c_out // groups)
+                    * eye[None, :, None, :, None]
+                ).reshape(s, groups * cg, c_out)
+                for w in parts
+            ]
+        return jnp.stack(parts)
+
     def macs_per_tile(self, c_in: int, c_out: int, groups: int = 1) -> int:
         p, s = self.planes, self.s_mix
         return (
@@ -484,7 +509,11 @@ class WinogradTransform(Transform):
     def kernel_transform(self, w):
         _, g, _ = winograd_matrices(self.m, self.k)
         g = jnp.asarray(g, w.dtype)
-        wt = jnp.einsum("xi,ijcd,yj->xycd", g, w, g)
+        # full float32 passes on a TPU: the tile kernel's GEMMs amplify
+        # a bfloat16-rounded kernel transform to ~1e-2 relative error
+        wt = jnp.einsum(
+            "xi,ijcd,yj->xycd", g, w, g, precision=jax.lax.Precision.HIGHEST
+        )
         return wt.reshape(self.t * self.t, w.shape[2], w.shape[3])
 
     def domain_dtype(self, dtype) -> jnp.dtype:

@@ -37,7 +37,8 @@ from repro.core.ioutil import atomic_write_text
 from repro.core.pipeline import fused_tile_conv
 from repro.kernels.fused_tile.blocks import BlockConfig
 
-_DEFAULT_WISDOM = pathlib.Path.home() / ".cache" / "repro_wisdom.json"
+# the checkout root: planning reads no state from outside the checkout
+_DEFAULT_WISDOM = pathlib.Path(__file__).resolve().parents[3] / "repro_wisdom.json"
 _CANDIDATES = (4, 8, 16, 24, 32, 48)
 _WISDOM_ENV = "REPRO_WISDOM"
 
@@ -144,13 +145,24 @@ def _load_cached(path: pathlib.Path) -> dict:
     return wisdom
 
 
+# TPU hardware models by `device_kind`, as JAX reports it
+TPU_MODELS = {"TPU v5 lite": analysis.TPU_V5E}
+
+
 def default_hw() -> analysis.HardwareModel:
-    """Hardware model for the current backend (paper machines on CPU)."""
-    return (
-        analysis.TPU_V5E
-        if jax.default_backend() == "tpu"
-        else analysis.SKYLAKE_X
-    )
+    """Hardware model of device 0: the TPU model for its `device_kind`
+    (a TPU missing from `TPU_MODELS` raises), the paper's SkylakeX
+    machine on any other backend."""
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return analysis.SKYLAKE_X
+    try:
+        return TPU_MODELS[dev.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no hardware model for TPU device_kind {dev.device_kind!r} "
+            f"(known: {sorted(TPU_MODELS)})"
+        ) from None
 
 
 def feasible_candidates(
@@ -287,9 +299,9 @@ def tuned_r(
 # ---------------------------------------------------------------------------
 # Block-shape wisdom for the parametric tile engine (kernels.fused_tile).
 #
-# A tuned entry's "blocks" field serializes a BlockConfig -- tile rows R,
-# tasks-per-program (0 = the matrix path's unchunked sweep) and the mix
-# unroll -- alongside the scan engine's "r".  Both ride the same
+# A tuned entry's "blocks" field serializes a BlockConfig -- tile rows R
+# and tasks-per-program (0 = the matrix path's unchunked sweep) --
+# alongside the scan engine's "r".  Both ride the same
 # backend:family:geometry key and the same stamped {gen, ts} envelope, so
 # atomic rewrites and staleness logic treat them as one entry.
 # ---------------------------------------------------------------------------

@@ -17,12 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-# jax.shard_map graduated from jax.experimental in newer releases
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 Pytree = Any
 
 _BLOCK = 256
@@ -82,7 +76,7 @@ def make_compressed_allreduce(mesh: Mesh, axis: str = "data"):
     """
 
     def one(g, r):
-        fn = _shard_map(
+        fn = jax.shard_map(
             functools.partial(compressed_psum, axis_name=axis),
             mesh=mesh,
             in_specs=(P(axis), P(axis)),

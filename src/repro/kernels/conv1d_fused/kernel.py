@@ -48,20 +48,10 @@ def conv1d_fused_call(
     l = lpad - (k - 1)
     assert l % lb == 0, (l, lb)
     body = functools.partial(_body, k=k, lb=lb, activation=activation)
-    # Overlapping (element-indexed) input blocks: per-dim pl.Element on
-    # newer jax, whole-spec unblocked indexing on older releases (the
-    # blocked dims are size-1 batch / zero-offset channels, so the same
-    # element-offset index map serves both).
-    if hasattr(pl, "Element"):
-        in_spec = pl.BlockSpec(
-            (1, pl.Element(lb + k - 1), d), lambda bi, li: (bi, li * lb, 0)
-        )
-    else:
-        in_spec = pl.BlockSpec(
-            (1, lb + k - 1, d),
-            lambda bi, li: (bi, li * lb, 0),
-            indexing_mode=pl.unblocked,
-        )
+    # overlapping (element-indexed) input blocks along the sequence
+    in_spec = pl.BlockSpec(
+        (1, pl.Element(lb + k - 1), d), lambda bi, li: (bi, li * lb, 0)
+    )
     return pl.pallas_call(
         body,
         grid=(bsz, l // lb),

@@ -13,8 +13,6 @@ One frozen record carries everything the autotuner can move:
                              "unchunked" -- the whole tile population in
                              one GEMM chain, which is what wins on large
                              cache-friendly CPUs.
-  * ``mix_block``         -- unroll factor of the S-point channel-mix
-                             loop (GEMM block over the K-of-S dimension)
 
 Serialized as a plain dict under the ``"blocks"`` field of a wisdom
 entry so it rides the existing ``backend:family:geometry`` keys and
@@ -31,7 +29,6 @@ from typing import Mapping, Optional
 class BlockConfig:
     r: int
     tasks_per_program: int = 0
-    mix_block: int = 8
 
     def chunk(self) -> int:
         """Tiles per sweep on the matrix path (0 = whole population)."""
@@ -43,7 +40,6 @@ class BlockConfig:
         return {
             "r": int(self.r),
             "tpp": int(self.tasks_per_program),
-            "mix": int(self.mix_block),
         }
 
     @classmethod
@@ -52,7 +48,6 @@ class BlockConfig:
             return cls(
                 r=int(d["r"]),
                 tasks_per_program=int(d.get("tpp", 0)),
-                mix_block=int(d.get("mix", 8)),
             )
         except (KeyError, TypeError, ValueError):
             return None

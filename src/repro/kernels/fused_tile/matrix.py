@@ -23,6 +23,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import tiling, transforms
+from repro.kernels.fused_tile.kernel import GEMM_PRECISION
+
+
+def _mm(a, b):
+    return jnp.matmul(a, b, precision=GEMM_PRECISION)
 
 
 def _run_tiles(
@@ -42,15 +47,17 @@ def _run_tiles(
     cgo = c_out // groups
 
     t1 = d.transpose(1, 0, 2).reshape(t * t, n * c_in)
-    u = (kf @ t1).reshape(p, s, n, groups, cgi)
+    u = _mm(kf, t1).reshape(p, s, n, groups, cgi)
     lhs = u.transpose(1, 3, 2, 0, 4).reshape(s, groups, n, p * cgi)
-    mm = jnp.einsum("sgnc,sgcd->sgnd", lhs, rhs)  # (S, g, N, P*C'/g)
+    mm = jnp.einsum(  # (S, g, N, P*C'/g)
+        "sgnc,sgcd->sgnd", lhs, rhs, precision=GEMM_PRECISION
+    )
     z = (
         mm.reshape(s, groups, n, p, cgo)
         .transpose(3, 0, 2, 1, 4)
         .reshape(p * s, n * c_out)
     )
-    y = (ki @ z).reshape(t_out, t_out, n, c_out).transpose(2, 0, 1, 3)
+    y = _mm(ki, z).reshape(t_out, t_out, n, c_out).transpose(2, 0, 1, 3)
     if epilogue is not None:
         # output tiles abut, so elementwise glue on tiles == on the
         # assembled output -- same contract as the task-scan engine
@@ -128,7 +135,7 @@ def staged_matrix_fns(
         c_in = tiles.shape[-1]
         n = b * plan.tiles_per_image
         d = tiles.reshape(n, t * t, c_in).astype(jnp.float32)
-        u = kf @ d.transpose(1, 0, 2).reshape(t * t, n * c_in)
+        u = _mm(kf, d.transpose(1, 0, 2).reshape(t * t, n * c_in))
         return u.reshape(p * s, n, c_in)  # transformed tiles, plane-major
 
     def stage2(u, wt):
@@ -140,7 +147,9 @@ def staged_matrix_fns(
             .transpose(1, 3, 2, 0, 4)
             .reshape(s, groups, n, p * cgi)
         )
-        return jnp.einsum("sgnc,sgcd->sgnd", lhs, rhs)
+        return jnp.einsum(
+            "sgnc,sgcd->sgnd", lhs, rhs, precision=GEMM_PRECISION
+        )
 
     def stage3(mm, batch):
         s_, g, n, pcgo = mm.shape
@@ -151,7 +160,7 @@ def staged_matrix_fns(
             .transpose(3, 0, 2, 1, 4)
             .reshape(p * s, n * c_out)
         )
-        y = (ki @ z).reshape(t_out, t_out, n, c_out).transpose(2, 0, 1, 3)
+        y = _mm(ki, z).reshape(t_out, t_out, n, c_out).transpose(2, 0, 1, 3)
         y6 = y.reshape(
             batch, plan.n_tiles_h, plan.n_tiles_w, t_out, t_out, c_out
         )

@@ -13,9 +13,10 @@
                              the accelerator runs
 
 Backend resolution: explicit argument > ``REPRO_TILE_BACKEND`` env var >
-``pallas`` on TPU, ``xla`` elsewhere.  f64 inputs have no f32 basis
-matrices and raise `UnsupportedSpec`, which the pipeline catches to fall
-back to the interpreting scan engine.
+``pallas`` on TPU, ``xla`` elsewhere.  The interpreter is refused on a
+TPU: there it would stand in for the compiled kernel without a trace.
+f64 inputs have no f32 basis matrices and raise `UnsupportedSpec`,
+which the pipeline catches to fall back to the interpreting scan engine.
 """
 
 from __future__ import annotations
@@ -64,10 +65,16 @@ class UnsupportedSpec(Exception):
 
 def resolve_backend(backend: Optional[str] = None) -> str:
     b = backend or os.environ.get(_ENV_BACKEND)
+    on_tpu = jax.default_backend() == "tpu"
     if b is None:
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
+        return "pallas" if on_tpu else "xla"
     if b not in _BACKENDS + ("scan",):
         raise ValueError(f"unknown tile backend {b!r}, expected {_BACKENDS}")
+    if on_tpu and b == "pallas_interpret":
+        raise ValueError(
+            "tile backend 'pallas_interpret' on a TPU: the interpreter "
+            "would stand in for the compiled kernel"
+        )
     return b
 
 
@@ -110,7 +117,6 @@ def conv2d_fused_tile(
         raise UnsupportedSpec("scan backend requested")
     if wt is None:
         wt = transform.kernel_transform(w)
-    rhs = spec.pack_rhs(wt, groups)
     blocks = blocks or BlockConfig(r=24)
 
     plan = tiling.TilePlan.build(x.shape[1], x.shape[2], spec.k, pad, spec.t)
@@ -132,15 +138,16 @@ def conv2d_fused_tile(
     if b == "xla":
         xp = tiling.pad_input(x, plan)
         y = _matrix.matrix_tile_conv(
-            xp, rhs, plan, spec, groups=groups, epilogue=epilogue,
+            xp, spec.pack_rhs(wt, groups), plan, spec, groups=groups,
+            epilogue=epilogue,
             chunk=blocks.chunk(),
         )
         return y.astype(x.dtype)
 
-    # Pallas paths: align the column tile count to r * tasks_per_program
+    # Pallas paths: align the column tile count to R * tasks_per_program
     # (extra zero columns, cropped after assembly) and lower the epilogue
-    # to its kernel form.
-    r = max(1, min(blocks.r, plan.n_tiles_w))
+    # to its kernel form.  R runs as whole sublane tiles.
+    r = _kernel.row_block(min(blocks.r, plan.n_tiles_w))
     tpp = max(1, blocks.tasks_per_program)
     while plan.n_tiles_w < r * tpp and tpp > 1:
         tpp -= 1
@@ -155,19 +162,17 @@ def conv2d_fused_tile(
         ep_ops, biases = epilogue.kernel_form()
     elif epilogue is not None:
         post = epilogue  # opaque callable: post-pass on assembled output
-    c_out = rhs.shape[1] * rhs.shape[3] // spec.planes
+    planes = spec.pack_planes(wt, groups)
     if biases is None:
-        biases = jnp.zeros((1, c_out), jnp.float32)
+        biases = jnp.zeros((1, planes.shape[-1]), jnp.float32)
 
     y = _kernel.fused_tile_call(
-        xp.astype(jnp.float32), rhs, biases,
+        xp.astype(jnp.float32), planes, biases,
         spec=spec,
         n_tiles_h=run_plan.n_tiles_h,
         n_tiles_w=run_plan.n_tiles_w,
         r=r,
         tasks_per_program=tpp,
-        mix_block=blocks.mix_block,
-        groups=groups,
         ep_ops=ep_ops,
         interpret=(b == "pallas_interpret"),
     )

@@ -1,5 +1,5 @@
-"""Elastic fleet serving under a simulated clock: sharded waves are
-bit-exact vs the single-replica oracle, replicas add simulated
+"""Elastic fleet serving under a simulated clock: sharded waves match
+the single-replica oracle to float32 rounding, replicas add simulated
 parallelism, the autoscaler grows/shrinks with hysteresis + admission
 control, crashed replicas orphan waves into bounded-retry re-dispatch,
 probes catch slow replicas and repair shared-cache corruption, and the
@@ -78,6 +78,17 @@ def _fleet(n=2, *, shards=1, clock=None, cfg=None, autoscaler=None,
     rt = FleetRuntime(pool, cfg, clock=clock,
                       autoscaler=autoscaler, adapt=adapt)
     return rt, clock
+
+
+def _assert_rows_match(got, want):
+    """Sharded rows run at a smaller batch than the unsharded wave, and
+    XLA may block a GEMM or convolution differently per batch size,
+    reordering float32 sums: equal to rounding (~1e-8 seen on outputs of
+    scale ~1e-1), not bitwise.  Bound: 1e-5 of the output's scale."""
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.shape == want.shape
+    scale = max(float(np.abs(want).max()), 1e-30)
+    assert float(np.abs(got - want).max()) <= 1e-5 * scale
 
 
 def _accounting(rt) -> dict:
@@ -169,7 +180,7 @@ def test_sharded_executor_bit_exact_on_ragged_wave():
         engine.compile(SPEC, ws, plan=net.plan, input_hw=(16, 16)),
         shards=3,
     )
-    assert np.array_equal(y1, np.asarray(sharded(x, ext)))
+    _assert_rows_match(sharded(x, ext), y1)
     # passthroughs keep the CompiledNet duck type intact
     assert sharded.spec is net.spec and sharded.cache is net.cache
 
@@ -211,7 +222,7 @@ def test_fleet_matches_single_replica_oracle_with_ragged_waves():
     oracle_out, _ = serve(1, shards=1)
     assert fleet_out.keys() == oracle_out.keys() == {a.rid for a in trace}
     for rid in oracle_out:
-        assert np.array_equal(fleet_out[rid], oracle_out[rid]), rid
+        _assert_rows_match(fleet_out[rid], oracle_out[rid])
     # the deadline-flushed waves make the exactness claim cover ragged
     # partial batches, not just full ones
     assert doc["scheduler"]["partial_waves"] >= 1

@@ -157,6 +157,63 @@ def test_backend_resolution_order(monkeypatch):
         resolve_backend(None)
 
 
+def test_interpreter_refused_on_tpu(monkeypatch):
+    """On a TPU the interpreter never stands in for the compiled kernel,
+    whether asked for by argument or by the env override."""
+    from repro.kernels.fused_tile import ops
+
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("REPRO_TILE_BACKEND", raising=False)
+    assert resolve_backend(None) == "pallas"
+    with pytest.raises(ValueError, match="interpret"):
+        resolve_backend("pallas_interpret")
+    monkeypatch.setenv("REPRO_TILE_BACKEND", "pallas_interpret")
+    with pytest.raises(ValueError, match="interpret"):
+        resolve_backend(None)
+
+
+@pytest.mark.parametrize(
+    "c_in,c_out,fits",
+    [(128, 128, True), (256, 256, False), (64, 130, False), (3, 64, True)],
+)
+def test_planner_excludes_what_the_kernel_cannot_hold(c_in, c_out, fits):
+    """A TPU plan takes a transformed family only where the Pallas
+    kernel can lay the layer out (channels fill one partial or whole
+    lane tiles) within its VMEM budget: FFT 256->256's stationary
+    right-hand matrices alone are ~75 MB."""
+    from repro.kernels.fused_tile import kernel
+
+    tr = transforms.FFTTransform(t=16, k=3)
+    assert kernel.kernel_fits(tr.kernel_spec(), c_in, c_out, 8) == fits
+    spec = ConvSpec(h=56, w=56, c_in=c_in, c_out=c_out, k=3, pad=1)
+    ap = registry.get("fft_fused").plan(spec, analysis.TPU_V5E)
+    assert np.isfinite(ap.cost) == fits
+    # the same layer on a model without the kernel is unaffected
+    assert np.isfinite(registry.get("fft_fused").plan(spec, BIG_HW).cost)
+
+
+@pytest.mark.parametrize("tpp,fits", [(0, True), (1, True), (16, False)])
+def test_kernel_gate_reads_the_tuned_blocks(tpp, fits):
+    """The gate sizes the kernel as it will launch: tuned blocks with
+    many tasks per program widen the strip and output blocks (FFT
+    128->128 at R=8: ~25 MiB at one task, ~74 MiB at sixteen)."""
+    fits_at = pipeline.TransformedAlgorithm._kernel_fits
+    tr = transforms.FFTTransform(t=16, k=3)
+    spec = ConvSpec(h=112, w=112, c_in=128, c_out=128, k=3, pad=1)
+    blocks = BlockConfig(r=8, tasks_per_program=tpp)
+    assert fits_at(tr, spec, 8, blocks) == fits
+    assert fits_at(tr, spec, 8, None)
+
+
+def test_kernel_vmem_budget_is_the_v5e_model_figure():
+    """The planner's fast-memory figure for the v5e is what one kernel
+    call may claim, not a separate guess."""
+    from repro.kernels.fused_tile import kernel
+
+    assert analysis.TPU_V5E.fast_shared_bytes == kernel.VMEM_BUDGET_BYTES
+    assert analysis.TPU_V5E.tile_backend == "pallas"
+
+
 def test_f64_gated_and_scan_fallback_exact(monkeypatch):
     """f64 is gated off the f32-basis kernel spec, and the dispatcher's
     scan fallback (the interpreting oracle) still serves exactly when
