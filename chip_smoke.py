@@ -28,6 +28,7 @@ import argparse
 import json
 import os
 import pathlib
+import re
 import sys
 import time
 
@@ -91,55 +92,50 @@ def rel_err(y, ref) -> float:
 
 def phase_plan(engine, spec, weights):
     """Plan at the bucket, lower one full wave and report per layer the
-    algorithm, params and tile backend its kernel dispatch resolved to."""
+    algorithm, params and tile backend its kernel dispatch resolved to
+    (a Pallas tile kernel is named `convserve_tile_<family>_t<T>` in
+    the lowered wave program)."""
     from repro.convserve import ReplicaPool, plan_net
     from repro.core import registry
     from repro.kernels.fused_tile import ops as tile_ops
 
     if os.environ.get(tile_ops._ENV_BACKEND):
         fail(f"{tile_ops._ENV_BACKEND} is set: the smoke runs the default")
+    backend = tile_ops.resolve_backend()
     plan = plan_net(spec, SIDE, SIDE, hw=engine.hw)
     pool = ReplicaPool.build(engine, spec, weights, n=1, plan=plan)
-    dispatches = []
-    prev = tile_ops.set_phase_hook(
-        lambda phase, info: dispatches.append(info)
-        if phase == "gather" else None
-    )
     t0 = time.perf_counter()
-    try:
-        c0 = spec.conv_layers()[0][1].c_in
-        lowered = pool.executors[0].lower(
-            np.zeros((MAX_BATCH, SIDE, SIDE, c0), np.float32),
-            np.full((MAX_BATCH, 2), SIDE, np.int32),
-        )
-    finally:
-        tile_ops.set_phase_hook(prev)
+    c0 = spec.conv_layers()[0][1].c_in
+    lowered = pool.executors[0].lower(
+        np.zeros((MAX_BATCH, SIDE, SIDE, c0), np.float32),
+        np.full((MAX_BATCH, 2), SIDE, np.int32),
+    )
     lower_s = time.perf_counter() - t0
-    by_family = {}
-    for info in dispatches:
-        by_family.setdefault(info["family"], set()).add(info["backend"])
+    text = lowered.as_text()
+    named = set(re.findall(r"convserve_tile_([a-z0-9]+)_t[0-9]+", text))
     tiled = [
         p for p in plan.layers if registry.get(p.algo).chain_family
     ]
+    families = set()
     print(f"[plan] {spec.name} at {SIDE}x{SIDE} on {engine.hw.name}; "
           f"fusion groups {[g.layers for g in plan.groups]}")
     for p in plan.layers:
         s = p.spec
-        backend = "xla"
+        resolved = "xla"
         if p in tiled:
             family = registry.get(p.algo).tile_algebra(p.algo_plan()).family
-            backend = ",".join(sorted(by_family.get(family, {"none"})))
+            families.add(family)
+            resolved = "pallas" if family in named else backend
         print(f"[plan]   layer {p.layer:2d} {s.h:3d}px {s.c_in:3d}->"
-              f"{s.c_out:<3d} {p.algo:10s} {p.params} backend={backend}")
-    calls = lowered.as_text().count("tpu_custom_call")
-    print(f"[plan] {len(tiled)} transformed layers, {len(dispatches)} tile "
-          f"dispatches, {calls} tpu_custom_call in the wave program")
+              f"{s.c_out:<3d} {p.algo:10s} {p.params} backend={resolved}")
+    calls = text.count("tpu_custom_call")
+    print(f"[plan] {len(tiled)} transformed layers, named tile kernels "
+          f"{sorted(named)}, {calls} tpu_custom_call in the wave program")
     print(f"[plan] kernel transforms prepared and wave program (batch "
           f"{MAX_BATCH}) traced and lowered in {lower_s:.2f}s")
-    backends = set().union(*by_family.values()) if by_family else set()
-    if backends - {"pallas"}:
-        fail(f"transformed layers resolved to {sorted(backends)}")
-    if len(dispatches) < len(tiled) or calls < len(tiled):
+    if tiled and backend != "pallas":
+        fail(f"transformed layers resolved to {[backend]}")
+    if families - named or calls < len(tiled):
         fail("a transformed layer does not run the compiled Pallas kernel")
     return plan, pool, lower_s
 

@@ -39,10 +39,10 @@ from repro.core import registry
 from repro.convserve.cache import KernelCache, weights_fingerprint
 from repro.convserve.graph import NetSpec
 from repro.convserve.obs.trace import (
+    CAT_HOST,
     CAT_PROFILE,
     CAT_STAGE,
     NULL_TRACER,
-    capture_tile_phases,
 )
 from repro.convserve.runtime.clock import Clock, RealClock
 from repro.convserve.plan import NetPlan
@@ -97,7 +97,10 @@ class _Extent:
         return _Extent(self.hs // window, self.ws // window)
 
     def mask(self, x, row0: int = 0):
-        return _mask_to_extent(x, self.hs, self.ws, row0) if self.live else x
+        if not self.live:
+            return x
+        with jax.named_scope("mask"):
+            return _mask_to_extent(x, self.hs, self.ws, row0)
 
 
 def _maxpool(x: jnp.ndarray, window: int) -> jnp.ndarray:
@@ -205,7 +208,8 @@ class NetExecutor:
         after the end-of-stage re-mask."""
         for op in ops:
             if op.kind == "maxpool":
-                x = _maxpool(x, op.window)
+                with jax.named_scope("pool"):
+                    x = _maxpool(x, op.window)
                 ext = ext.after_pool(op.window)
             elif op.kind == "bias":
                 x = x + ws[op.layer]
@@ -260,17 +264,22 @@ class NetExecutor:
         return cur.mask(x), cur
 
     def _forward(self, x, ws, wts, sizes):
+        """The wave program.  Named scopes put `prologue`, each
+        `stage<i>.<label>` and each `mask` and `pool` into the ops'
+        metadata, for profiles and lowered text."""
         ext = _Extent(
             sizes[:, 0] if sizes is not None else None,
             sizes[:, 1] if sizes is not None else None,
         )
-        x = ext.mask(x)
-        if self.program.prologue:
-            x, ext = self._apply_tail(x, self.program.prologue, ext, ws)
+        with jax.named_scope("prologue"):
             x = ext.mask(x)
-        for stage in self.program.stages:
+            if self.program.prologue:
+                x, ext = self._apply_tail(x, self.program.prologue, ext, ws)
+                x = ext.mask(x)
+        for i, stage in enumerate(self.program.stages):
             run = self._run_fused if stage.fused else self._run_single
-            x, ext = run(stage, x, ws, wts, ext)
+            with jax.named_scope(f"stage{i}.{stage.label}"):
+                x, ext = run(stage, x, ws, wts, ext)
         return x
 
     # -------------------------------------------------------- public API
@@ -340,11 +349,13 @@ class NetExecutor:
         """
         x = jnp.asarray(x, self.dtype)
         sizes = self._validate_call(x, sizes)
-        wts = self._fetch_transforms()
-        fn = self._program(x, sizes, mesh)
-        self.calls += 1
-        self.images += int(x.shape[0])
-        return fn(x, self.weights, wts, sizes)
+        with self.tracer.span("convserve.exec.transforms", CAT_HOST):
+            wts = self._fetch_transforms()
+        with self.tracer.span("convserve.exec.launch", CAT_HOST):
+            fn = self._program(x, sizes, mesh)
+            self.calls += 1
+            self.images += int(x.shape[0])
+            return fn(x, self.weights, wts, sizes)
 
     def lower(self, x: jnp.ndarray, sizes: Optional[jnp.ndarray] = None,
               *, mesh=None):
@@ -397,11 +408,7 @@ class NetExecutor:
                     f"stage:{stage.label}", CAT_STAGE,
                     stage=stage.label, fused=stage.fused,
                 ):
-                    # the phase hook fires while jit traces the stage --
-                    # the warm-up compile below announces gather/GEMM/mix
-                    # phases as instants nested under this stage span
-                    with capture_tile_phases(tr, stage=stage.label):
-                        jax.block_until_ready(fn(*args))  # compile untimed
+                    jax.block_until_ready(fn(*args))  # compile untimed
                     t0 = self.clock.now()
                     y, hs, ws_cols = fn(*args)
                     x = jax.block_until_ready(y)

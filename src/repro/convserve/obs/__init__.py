@@ -21,7 +21,7 @@ from repro.convserve.obs.export import (
 from repro.convserve.obs.trace import (
     CAT_ADAPT,
     CAT_FLEET,
-    CAT_PHASE,
+    CAT_HOST,
     CAT_PROFILE,
     CAT_REQUEST,
     CAT_ROOFLINE,
@@ -34,17 +34,16 @@ from repro.convserve.obs.trace import (
     Span,
     Tracer,
     attach,
-    capture_tile_phases,
     span_index,
     span_tree_signature,
 )
 
 __all__ = [
-    "CAT_ADAPT", "CAT_FLEET", "CAT_PHASE", "CAT_PROFILE", "CAT_REQUEST",
+    "CAT_ADAPT", "CAT_FLEET", "CAT_HOST", "CAT_PROFILE", "CAT_REQUEST",
     "CAT_ROOFLINE", "CAT_SCALE", "CAT_STAGE", "CAT_WAVE",
     "FlightRecorder", "InstantEvent", "NULL_TRACER", "NullTracer", "Span",
     "TRIP_SLO_BREACH", "TRIP_VERIFICATION", "TRIP_WAVE_LOSS", "Tracer",
-    "attach", "capture_tile_phases", "chrome_trace_events",
+    "attach", "chrome_trace_events",
     "prometheus_text", "roofline_table", "span_index",
     "span_tree_signature", "validate_chrome_trace", "write_trace",
 ]

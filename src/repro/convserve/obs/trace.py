@@ -1,10 +1,10 @@
 """Flight-recorder spans: a low-overhead, Clock-routed trace ring.
 
 One `Tracer` per serving stack records the full causal chain -- admit
--> queue -> wave formation -> replica dispatch -> per-stage execute ->
-tile-engine phases -- as `Span`s (durations) and `InstantEvent`s
-(points: faults, scale decisions, adapt verdicts).  Three properties
-make it serving-grade:
+-> queue -> wave formation -> replica dispatch -> the wave's host
+phases -> per-stage execute -- as `Span`s (durations) and
+`InstantEvent`s (points: faults, scale decisions, adapt verdicts).
+Three properties make it serving-grade:
 
   * **Clock-routed**: every timestamp comes from the injected `Clock`.
     Under a `SimClock` the whole trace is deterministic -- the same
@@ -25,9 +25,20 @@ Cross-thread spans (a wave begins on the dispatch thread and ends on a
 replica completion thread) use the explicit `begin()`/`end()` API with
 the span id carried by the caller; same-thread nesting uses the
 `span()` context manager, which maintains the parent stack in a
-thread-local.  Components default to the no-op `NULL_TRACER`, so an
-uninstrumented runtime pays one attribute load per site and nothing
-else.
+thread-local.  Spans that a replica thread opens for a wave pass the
+wave span's id as `parent`, so they are kept or dropped with it and the
+sampled set does not depend on thread timing.  Components default to the no-op `NULL_TRACER`, so an
+uninstrumented runtime records nothing in a ring.
+
+Every `span()` -- of a `Tracer` and of the `NullTracer` alike -- also
+enters `jax.profiler.TraceAnnotation(name, **args)` for its duration, so
+the same spans land on the profiler's timeline beside the device's ops
+whenever a profiler is recording (and cost about a microsecond when
+none is).  The annotation carries the span's scalar args plus those of
+the spans it nests in on the same thread: a replica's `wave=<id>`
+reaches the executor's spans inside it.  The cross-thread `begin()`/
+`end()` spans (`request:*`, `wave:*`) stay in the ring only: a profiler
+event opens and closes on one thread.
 """
 
 from __future__ import annotations
@@ -37,11 +48,13 @@ import contextlib
 import threading
 from typing import Dict, Iterable, List, Optional, Tuple
 
+import jax
+
 # event categories (the span taxonomy; see README "Observability")
 CAT_REQUEST = "request"  # admit -> result, one span per rid
 CAT_WAVE = "wave"  # dispatch -> completion, one span per wave
 CAT_STAGE = "stage"  # one ExecProgram stage's timed execution
-CAT_PHASE = "phase"  # tile-engine phase instants (gather/GEMM/...)
+CAT_HOST = "host"  # a wave's host phases: runtime loop, replica, executor
 CAT_PROFILE = "profile"  # a profile_stages sweep
 CAT_FLEET = "fleet"  # replica lifecycle / fault instants
 CAT_SCALE = "scale"  # autoscaler decisions
@@ -49,6 +62,33 @@ CAT_ADAPT = "adapt"  # replan / shadow / promote / rollback
 CAT_ROOFLINE = "roofline"  # per-stage attribution rows as instants
 
 _DROPPED = -1  # stack sentinel: children of a sampled-out root
+
+# span() keywords that place the ring span rather than describe it
+_PLACEMENT = frozenset(("parent", "pid", "tid", "flow_in", "flow_out"))
+_SCALARS = (int, float, str)
+
+# Per thread, the annotation args of the spans open on it.  Profiler
+# annotations nest per thread across every tracer in the process, as
+# the profiler itself is per process.
+_annotation_args = threading.local()
+
+
+@contextlib.contextmanager
+def _annotate(name: str, kw: dict):
+    """Enter `jax.profiler.TraceAnnotation` for one span: its scalar
+    args over those of the spans it nests in on this thread."""
+    stack = getattr(_annotation_args, "stack", None)
+    if stack is None:
+        stack = _annotation_args.stack = [{}]
+    args = dict(stack[-1])
+    args.update((k, v) for k, v in kw.items()
+                if k not in _PLACEMENT and isinstance(v, _SCALARS))
+    stack.append(args)
+    try:
+        with jax.profiler.TraceAnnotation(name, **args):
+            yield
+    finally:
+        stack.pop()
 
 
 class Span:
@@ -157,13 +197,16 @@ class Tracer:
         **args,
     ) -> int:
         """Open a span; returns its id (0 when disabled or sampled out).
-        The id is plain data -- `end()` may run on another thread."""
+        The id is plain data -- `end()` may run on another thread, and
+        it may be passed as the `parent` of spans begun on others: a
+        `parent` of 0 drops the span with its sampled-out parent.  Only
+        roots advance the sampling counter."""
         if not self.enabled:
             return 0
         stack = self._stack()
         if parent is None and stack:
             parent = stack[-1]
-        if parent == _DROPPED:
+        if parent == _DROPPED or parent == 0:
             return 0  # child of a sampled-out root: drop the whole tree
         t0 = self.clock.now()
         hint = getattr(self._tls, "flow_hint", None)
@@ -214,22 +257,24 @@ class Tracer:
     @contextlib.contextmanager
     def span(self, name: str, cat: str = CAT_REQUEST, **kw):
         """Same-thread nested span: children begun inside parent under
-        this tracer on this thread."""
-        sid = self.begin(name, cat, **kw)
-        stack = self._stack()
-        stack.append(sid if sid else _DROPPED)
-        try:
-            yield sid
-        finally:
-            stack.pop()
-            self.end(sid)
+        this tracer on this thread.  Also a profiler annotation (see
+        the module docstring)."""
+        with _annotate(name, kw):
+            sid = self.begin(name, cat, **kw)
+            stack = self._stack()
+            stack.append(sid if sid else _DROPPED)
+            try:
+                yield sid
+            finally:
+                stack.pop()
+                self.end(sid)
 
     def instant(
         self, name: str, cat: str = CAT_FLEET, *, pid: int = 0, tid: int = 0,
         **args,
     ) -> None:
-        """Record one point event (fault, scale decision, adapt verdict,
-        tile phase)."""
+        """Record one point event (fault, scale decision, adapt
+        verdict)."""
         if not self.enabled:
             return
         stack = self._stack()
@@ -288,7 +333,8 @@ class Tracer:
 
 
 class NullTracer:
-    """The no-op default: instrumented code pays one method call."""
+    """The no-op default: records nothing; its `span()` is only the
+    profiler annotation."""
 
     active = False
     enabled = False
@@ -301,8 +347,9 @@ class NullTracer:
         return None
 
     @contextlib.contextmanager
-    def span(self, *a, **kw):
-        yield 0
+    def span(self, name: str, cat: str = CAT_REQUEST, **kw):
+        with _annotate(name, kw):
+            yield 0
 
     def instant(self, *a, **kw) -> None:
         return None
@@ -325,30 +372,6 @@ class NullTracer:
 
 
 NULL_TRACER = NullTracer()
-
-
-@contextlib.contextmanager
-def capture_tile_phases(tracer, **extra):
-    """Route the tile engine's phase hook into `tracer` for the duration:
-    every `conv2d_fused_tile` dispatch inside emits one instant per
-    logical phase (gather -> forward GEMM -> mix -> inverse GEMM ->
-    scatter) carrying the kernel geometry.  Phases of one fused kernel
-    are not separately timeable (they live inside a single compiled
-    program), so these fire at dispatch/trace time; the roofline pass
-    splits a stage's measured seconds across them by per-phase FLOPs."""
-    if tracer is None or not getattr(tracer, "enabled", False):
-        yield
-        return
-    from repro.kernels.fused_tile import ops as tile_ops
-
-    def hook(phase: str, info: dict) -> None:
-        tracer.instant(f"phase:{phase}", CAT_PHASE, **info, **extra)
-
-    prev = tile_ops.set_phase_hook(hook)
-    try:
-        yield
-    finally:
-        tile_ops.set_phase_hook(prev)
 
 
 def attach(obj, tracer) -> None:

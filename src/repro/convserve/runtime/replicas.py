@@ -21,6 +21,7 @@ import dataclasses
 import jax
 import numpy as np
 
+from repro.convserve.obs.trace import CAT_HOST, NULL_TRACER
 from repro.convserve.runtime.clock import Clock, RealClock
 from repro.convserve.runtime.scheduler import Wave
 
@@ -28,9 +29,10 @@ from repro.convserve.runtime.scheduler import Wave
 @dataclasses.dataclass
 class WaveResult:
     """One executed wave: per-request outputs plus where/how long.
-    `compiled` marks a cold wave (the replica jitted a new program for
-    this shape): its wall time is compile + compute, so the runtime
-    keeps it out of the deadline-slack service estimate."""
+    `compute_s` runs from the host-to-device put through the fetch of
+    the output.  `compiled` marks a cold wave (the replica jitted a new
+    program for this shape): its wall time is compile + compute, so the
+    runtime keeps it out of the deadline-slack service estimate."""
 
     wave: Wave
     outputs: Dict[int, np.ndarray]  # rid -> (H', W', C')
@@ -47,6 +49,11 @@ class ReplicaPool:
     were built against the SAME `KernelCache` -- asserted here, because
     separate caches would silently re-transform every kernel per
     replica.
+
+    Each wave's host path is traced on `tracer` as a
+    `convserve.replica.run` span, under the wave's span, holding
+    `assemble`, `put`, `compute`, `fetch` and `crop` children (see
+    `obs.trace`).
     """
 
     def __init__(self, executors: Sequence, *, workers: Optional[int] = None,
@@ -66,6 +73,7 @@ class ReplicaPool:
         self.spec = spec
         self.cache = cache
         self.clock = clock or RealClock()
+        self.tracer = NULL_TRACER  # ServeRuntime attaches its own
         self.workers = len(executors) if workers is None else workers
         self._pool = (
             ThreadPoolExecutor(
@@ -114,16 +122,28 @@ class ReplicaPool:
             return i, self.executors[i]
 
     def _run(self, i: int, ex, wave: Wave) -> WaveResult:
+        tr = self.tracer
         try:
-            batch, sizes = wave.assemble()
-            before = ex.compile_count
-            t0 = self.clock.now()
-            y = ex(batch, sizes)
-            y = np.asarray(jax.block_until_ready(y))
-            dt = self.clock.now() - t0
+            with tr.span("convserve.replica.run", CAT_HOST,
+                         parent=wave.trace_parent, replica=i,
+                         **wave.trace_args):
+                with tr.span("convserve.replica.assemble", CAT_HOST):
+                    batch, sizes = wave.assemble()
+                before = ex.compile_count
+                t0 = self.clock.now()
+                with tr.span("convserve.replica.put", CAT_HOST):
+                    batch, sizes = jax.block_until_ready(
+                        jax.device_put((batch, sizes))
+                    )
+                with tr.span("convserve.replica.compute", CAT_HOST):
+                    y = jax.block_until_ready(ex(batch, sizes))
+                with tr.span("convserve.replica.fetch", CAT_HOST):
+                    y = np.asarray(y)
+                dt = self.clock.now() - t0
+                with tr.span("convserve.replica.crop", CAT_HOST):
+                    outputs = wave.crop(self.spec, y)
             return WaveResult(
-                wave=wave, outputs=wave.crop(self.spec, y),
-                replica=i, compute_s=dt,
+                wave=wave, outputs=outputs, replica=i, compute_s=dt,
                 compiled=ex.compile_count > before,
             )
         finally:

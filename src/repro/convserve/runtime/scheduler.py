@@ -85,17 +85,29 @@ class RuntimeConfig:
 @dataclasses.dataclass
 class Wave:
     """One dispatchable batch: like-bucketed requests plus the padded
-    batch size the executor will see."""
+    batch size the executor will see.  `wave_id` counts the waves its
+    scheduler formed, from 1.  `trace_parent` is the id of the wave's
+    span in the runtime's tracer, under which the spans of its host path
+    nest (0 when the wave was sampled out; None when nothing traces
+    it)."""
 
     bucket: int
     requests: List[Request]
     batch_size: int
     reason: str  # FLUSH_FULL | FLUSH_DEADLINE | FLUSH_DRAIN
     formed_at: float
+    wave_id: int = 0
+    trace_parent: Optional[int] = None
 
     @property
     def partial(self) -> bool:
         return self.reason != FLUSH_FULL
+
+    @property
+    def trace_args(self) -> dict:
+        """What every span of this wave's host path carries."""
+        return {"wave": self.wave_id, "bucket": self.bucket,
+                "batch": self.batch_size, "rows": len(self.requests)}
 
     def assemble(self) -> tuple:
         """(batch, sizes): requests zero-padded into the bucket square
@@ -332,6 +344,7 @@ class WaveScheduler:
             batch_size=size,
             reason=reason,
             formed_at=now,
+            wave_id=self.waves,
         )
 
     def clear(self) -> int:

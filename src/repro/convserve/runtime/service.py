@@ -30,6 +30,7 @@ from typing import Dict, List, Optional, Sequence
 import numpy as np
 
 from repro.convserve.obs.trace import (
+    CAT_HOST,
     CAT_REQUEST,
     CAT_WAVE,
     NULL_TRACER,
@@ -83,6 +84,8 @@ class ServeRuntime:
         # in-flight wave spans, keyed by the pool future's identity
         self._wave_ctx: Dict[int, int] = {}  # guarded-by: _lock
         if self.tracer.active:
+            if isinstance(self.pool, ReplicaPool):
+                self.pool.tracer = self.tracer
             for ex in getattr(self.pool, "executors", ()):
                 attach_tracer(ex, self.tracer)
 
@@ -185,12 +188,6 @@ class ServeRuntime:
         now = self.clock.now()
         for r in wave.requests:
             r.t_dispatch = now
-        with self._lock:
-            self._outstanding += 1
-        self.telemetry.inc("waves")
-        self.telemetry.inc(f"waves.{wave.reason}")
-        if wave.partial:
-            self.telemetry.inc("partial_waves")
         # the wave span opens on the dispatch thread and closes on a
         # replica completion thread: explicit begin/end, id carried in
         # _wave_ctx keyed by the pool future (registered BEFORE the
@@ -201,11 +198,28 @@ class ServeRuntime:
             bucket=wave.bucket, n=len(wave.requests),
             reason=wave.reason, partial=wave.partial,
         )
-        fut = self.pool.submit(wave)
-        if sid:
+        # the wave's host spans, on whichever thread, nest under its
+        # span: kept or dropped with it, they advance no sampling count
+        if self.tracer.active:
+            wave.trace_parent = sid
+        oldest = min(r.t_admit for r in wave.requests)
+        with self.tracer.span(
+            "convserve.runtime.dispatch", CAT_HOST,
+            parent=wave.trace_parent,
+            queue_wait_max_us=round(1e6 * (now - oldest)),
+            **wave.trace_args,
+        ):
             with self._lock:
-                self._wave_ctx[id(fut)] = sid
-        fut.add_done_callback(self._on_done)
+                self._outstanding += 1
+            self.telemetry.inc("waves")
+            self.telemetry.inc(f"waves.{wave.reason}")
+            if wave.partial:
+                self.telemetry.inc("partial_waves")
+            fut = self.pool.submit(wave)
+            if sid:
+                with self._lock:
+                    self._wave_ctx[id(fut)] = sid
+            fut.add_done_callback(self._on_done)
 
     def _close_wave_span(self, fut, wave: Optional[Wave], **args) -> None:
         """Close the wave span opened at dispatch (and the request spans
@@ -233,6 +247,13 @@ class ServeRuntime:
                 self._outstanding -= 1
                 self._done_cv.notify_all()
             return
+        with self.tracer.span("convserve.runtime.complete", CAT_HOST,
+                              parent=res.wave.trace_parent,
+                              **res.wave.trace_args):
+            self._complete(fut, res)
+
+    def _complete(self, fut, res: WaveResult) -> None:
+        """A wave's results, counters, spans and observers."""
         done = self.clock.now()
         wave = res.wave
         if res.compiled:

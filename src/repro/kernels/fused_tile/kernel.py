@@ -333,4 +333,5 @@ def fused_tile_call(
             vmem_limit_bytes=min(need + need // 4, VMEM_BUDGET_BYTES)
         ),
         interpret=interpret,
+        name=f"convserve_tile_{spec.family}_t{t}",
     )(xp, rhs, kf, ki, biases)

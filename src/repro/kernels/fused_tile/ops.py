@@ -35,29 +35,6 @@ from repro.kernels.fused_tile.blocks import BlockConfig
 _BACKENDS = ("xla", "pallas", "pallas_interpret")
 _ENV_BACKEND = "REPRO_TILE_BACKEND"
 
-# The tile engine's logical phases, in execution order.  One fused
-# dispatch runs all five inside a single compiled program, so they are
-# announced (via the phase hook) rather than separately timed; the
-# observability layer splits measured stage time across the GEMM phases
-# by their MAC counts.
-_PHASES = ("gather", "forward_gemm", "mix", "inverse_gemm", "scatter")
-
-# Observability hook: when set (see obs.trace.capture_tile_phases), each
-# conv2d_fused_tile dispatch calls it once per logical phase with
-# (phase, info) where info carries the resolved backend + geometry.
-# Fires at dispatch/trace time, not inside the jitted kernel.
-_PHASE_HOOK = None
-
-
-def set_phase_hook(hook):
-    """Install the phase announcement hook; returns the previous one so
-    callers can restore it (see `obs.trace.capture_tile_phases`)."""
-    global _PHASE_HOOK
-    prev = _PHASE_HOOK
-    _PHASE_HOOK = hook
-    return prev
-
-
 class UnsupportedSpec(Exception):
     """The parametric engine cannot run this problem; callers fall back
     to the interpreting scan engine."""
@@ -120,20 +97,6 @@ def conv2d_fused_tile(
     blocks = blocks or BlockConfig(r=24)
 
     plan = tiling.TilePlan.build(x.shape[1], x.shape[2], spec.k, pad, spec.t)
-
-    if _PHASE_HOOK is not None:
-        info = {
-            "backend": b,
-            "family": transform.family,
-            "t": spec.t,
-            "t_out": spec.t_out,
-            "planes": spec.planes,
-            "n_tiles_h": plan.n_tiles_h,
-            "n_tiles_w": plan.n_tiles_w,
-            "groups": groups,
-        }
-        for phase in _PHASES:
-            _PHASE_HOOK(phase, info)
 
     if b == "xla":
         xp = tiling.pad_input(x, plan)

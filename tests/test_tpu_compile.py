@@ -9,6 +9,7 @@ worker that runs this file loads the TPU library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -101,6 +102,26 @@ def test_tile_kernel_compiles_for_v5e(case, one_chip, no_cache):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("case", ["wino-64-128-112", "fft-64-128-112"])
+def test_tile_kernel_is_named_in_the_compiled_program(case, one_chip,
+                                                      no_cache):
+    """The profiler shows a device op by its HLO instruction's name: the
+    tile kernel's is `convserve_tile_<family>_t<T>`."""
+    tr, side, c_in, c_out, groups = CASES[case]
+
+    def conv(x, w):
+        return conv2d_fused_tile(x, w, tr, pad=1, backend="pallas",
+                                 blocks=BlockConfig(r=8))
+
+    x = jax.ShapeDtypeStruct((WAVE, side, side, c_in), jnp.float32,
+                             sharding=one_chip)
+    w = jax.ShapeDtypeStruct((3, 3, c_in, c_out), jnp.float32,
+                             sharding=one_chip)
+    text = jax.jit(conv).lower(x, w).compile().as_text()
+    name = f"convserve_tile_{tr.family}_t{tr.kernel_spec().t}"
+    assert f"%{name}" in text and "tpu_custom_call" in text
+
+
 def test_sharded_wave_compiles_per_device_on_four_chips(
     data_mesh, monkeypatch, no_cache
 ):
@@ -141,3 +162,41 @@ def test_sharded_wave_compiles_per_device_on_four_chips(
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= len(tiled)
     assert "all-gather" not in text
+
+
+def test_planned_wave_names_its_tile_kernels_in_the_lowered_text(
+    one_chip, monkeypatch, no_cache
+):
+    """What `chip_smoke.py` checks: the lowered one-chip wave program
+    names a `convserve_tile_<family>_t<T>` kernel for every transform
+    family the v5e plan uses."""
+    monkeypatch.setenv("REPRO_TILE_BACKEND", "pallas")
+    spec = vgg_mixed_channel(c_in=3)
+    side = 64
+    net = Engine(hw=analysis.TPU_V5E).compile(
+        spec, init_weights(spec, seed=0), input_hw=(side, side)
+    )
+    families = {
+        registry.get(p.algo).tile_algebra(p.algo_plan()).family
+        for p in net.plan.layers if registry.get(p.algo).chain_family
+    }
+    assert families, "the v5e plan should transform some layers"
+    ex = net.executor
+    x_shape = (WAVE, side, side, 3)
+    fn = ex._program(np.empty(x_shape), np.empty((WAVE, 2)), None)
+
+    def shapes(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            tree,
+        )
+
+    text = fn.lower(
+        jax.ShapeDtypeStruct(x_shape, jnp.float32, sharding=one_chip),
+        shapes(ex.weights),
+        shapes(ex._fetch_transforms()),
+        jax.ShapeDtypeStruct((WAVE, 2), jnp.int32, sharding=one_chip),
+    ).as_text()
+    named = set(re.findall(r"convserve_tile_([a-z0-9]+)_t[0-9]+", text))
+    assert named == families
