@@ -25,7 +25,7 @@ import time
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 
-def _control(cfg, weights, images, win, precision):
+def _control(net, cfg, weights, images, win, precision):
     """The window as it would read with the reference at `precision` in
     the program's place: each answered request's answer replaced by the
     control's for the same image."""
@@ -33,7 +33,7 @@ def _control(cfg, weights, images, win, precision):
 
     keys = sorted({(r["side"], r["image"]) for r in win.requests
                    if r["rid"] in win.results})
-    outs = reference.run(cfg["layers"], weights,
+    outs = reference.run(net, cfg, weights,
                          [images[s][k] for s, k in keys], precision)
     ctl = dict(zip(keys, outs))
     results = {r["rid"]: ctl[(r["side"], r["image"])] for r in win.requests
@@ -67,13 +67,13 @@ def main(argv=None) -> int:
         t = time.monotonic()
         server = harness.prepare(cfg, mix, seed)
         win = harness.drive(server, args.seconds, seed)
-        weights, images = server.weights, server.images
+        net, weights, images = server.net, server.weights, server.images
         harness.release(server)
         row = {"seed": seed, "answers": len(win.results)}
         for name, served in [("program", win)] + [
-                (prec, _control(cfg, weights, images, win, prec))
+                (prec, _control(net, cfg, weights, images, win, prec))
                 for prec in ("high", "bf16")]:
-            got = harness.check(cfg, weights, images, served)
+            got = harness.check(net, cfg, weights, images, served)
             got["compiles_in_window"] = win.compiles
             line = harness.limits_line(got, cfg["rel_err_limit"])
             row[name] = got["worst_rel_err"]

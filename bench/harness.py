@@ -1,10 +1,12 @@
 """One run of one cell: set the server up, drive it for a window, check
 what it served against the reference, and report.
 
-The cell, its configuration, its traffic mix and its metrics are all
-found by name: the cell in `BENCHMARK.json`, the configuration in
-`bench/configs/<config>.json`, the mix in `bench/traffic/<traffic>.json`
-and each metric's reader in `bench/metrics/<metric>.py`.
+The cell, its configuration, its net, its traffic mix and its metrics
+are all found by name: the cell in `BENCHMARK.json`, the configuration
+in `bench/configs/<config>.json`, the module of the net it names in
+`bench/nets/<net>.py` (`bench/nets/__init__.py`), the mix in
+`bench/traffic/<traffic>.json` and each metric's reader in
+`bench/metrics/<metric>.py`.
 
 The served path is the one a user calls: `Engine` plans at the largest
 bucket, `ReplicaPool.build` binds the weights, `ServeRuntime` admits,
@@ -29,7 +31,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from bench import reference, traffic, work
+from bench import nets, reference, traffic, work
 
 BENCH_DIR = pathlib.Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
@@ -85,27 +87,6 @@ def load_reader(name: str, directory: pathlib.Path = METRIC_DIR):
     return mod.read
 
 
-def netspec(cfg: dict):
-    """The configuration as the program's `NetSpec`."""
-    from repro.convserve.graph import NetSpec, bias, conv, maxpool, relu
-
-    layers = []
-    for lay in cfg["layers"]:
-        if lay["kind"] == "conv":
-            layers.append(conv(lay["c_in"], lay["c_out"], k=lay.get("k", 3),
-                               stride=lay.get("stride", 1),
-                               groups=lay.get("groups", 1)))
-        elif lay["kind"] == "bias":
-            layers.append(bias(lay["c"]))
-        elif lay["kind"] == "relu":
-            layers.append(relu())
-        elif lay["kind"] == "maxpool":
-            layers.append(maxpool(lay.get("window", 2)))
-        else:
-            raise ValueError(f"unknown layer kind {lay['kind']!r}")
-    return NetSpec(name=cfg["name"], layers=tuple(layers))
-
-
 # ------------------------------------------------------------------ device
 
 def configure(root: pathlib.Path = ROOT) -> None:
@@ -159,6 +140,7 @@ def memory_peak_bytes() -> int:
 @dataclasses.dataclass
 class Server:
     cfg: dict
+    net: object  # the configuration's net module
     mix: dict
     weights: dict
     images: dict  # side -> [HWC]
@@ -177,9 +159,11 @@ class Server:
             self.recorder.observe(res)
 
 
-def prepare(cfg: dict, mix: dict, seed: int) -> Server:
+def prepare(cfg: dict, mix: dict, seed: int,
+            net_dir: pathlib.Path = nets.NET_DIR) -> Server:
     """Weights and images from the seed, the server planned, built and
-    warmed at the cell's (bucket, max_batch) programs only."""
+    warmed at the cell's (bucket, max_batch) programs only; the net is
+    the module the configuration names, found in `net_dir`."""
     phases = {}
     t = time.monotonic()
     from repro.convserve import (Engine, ReplicaPool, RuntimeConfig,
@@ -192,12 +176,12 @@ def prepare(cfg: dict, mix: dict, seed: int) -> Server:
         t = now
 
     lap("import_program")
-    c_in = cfg["layers"][0]["c_in"]
-    images = traffic.make_images(mix, seed, c_in)
+    net = nets.load(cfg, net_dir)
+    images = traffic.make_images(mix, seed, net.input_channels(cfg))
     lap("images")
-    weights = reference.make_weights(cfg["layers"], seed)
+    weights = net.make_weights(cfg, seed)
     lap("weights")
-    spec = netspec(cfg)
+    spec = net.netspec(cfg)
     top = max(mix["buckets"])
     engine = Engine()
     pool = ReplicaPool.build(engine, spec, dict(weights), n=mix["replicas"],
@@ -208,7 +192,8 @@ def prepare(cfg: dict, mix: dict, seed: int) -> Server:
     lap("plan_and_bind")
     rt.warmup()
     lap("warmup")
-    server = Server(cfg, mix, weights, images, engine, pool, rt, phases=phases)
+    server = Server(cfg, net, mix, weights, images, engine, pool, rt,
+                    phases=phases)
     rt.add_wave_observer(server.observe)
     return server
 
@@ -466,15 +451,15 @@ def release(server: Server) -> None:
 
 # ------------------------------------------------------------------- check
 
-def check(cfg: dict, weights: dict, images: dict, win: Window,
+def check(net, cfg: dict, weights: dict, images: dict, win: Window,
           precision: str = "highest") -> dict:
     """Every answer of the window (and of a traced segment) against the
-    reference's answer for the same image: the worst error, and how
-    many answers never came."""
+    reference's answer (the net module's `forward`) for the same image:
+    the worst error, and how many answers never came."""
     requests, results = _served(win)
     keys = sorted({(r["side"], r["image"]) for r in requests
                    if r["rid"] in results})
-    refs = reference.run(cfg["layers"], weights,
+    refs = reference.run(net, cfg, weights,
                          [images[s][k] for s, k in keys], precision)
     ref = dict(zip(keys, refs))
     worst = 0.0
@@ -514,6 +499,7 @@ class Run:
     setup_s: float
     window: Window
     trace: object = None  # trace_reduce.Reduced, in a traced run
+    net: object = None  # the configuration's net module
 
 
 def read_metrics(run: Run, specs: List[dict]) -> dict:
@@ -551,7 +537,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
              require_chip: bool = True, peaks: Optional[dict] = None,
              trace_dir: Optional[str] = None, out=sys.stdout,
              err=sys.stderr, config_dir: pathlib.Path = CONFIG_DIR,
-             traffic_dir: pathlib.Path = traffic.TRAFFIC_DIR) -> dict:
+             traffic_dir: pathlib.Path = traffic.TRAFFIC_DIR,
+             net_dir: pathlib.Path = nets.NET_DIR) -> dict:
     """One run of a cell, as `bench/run.py` makes it; returns the
     result line it printed."""
     bench = bench or load_benchmark()
@@ -568,7 +555,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         device = {"platform": d0.platform, "kind": d0.device_kind,
                   "count": len(jax.devices())}
     t_prep = time.monotonic()
-    server = prepare(cfg, mix, seed)
+    server = prepare(cfg, mix, seed, net_dir)
     phases = {"start_to_prepare": t_prep - t_start, **server.phases}
     if trace:
         import shutil
@@ -580,7 +567,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     print("setup phases (s): " + ", ".join(
         f"{k} {v:.3f}" for k, v in phases.items()), file=err)
     device["memory_peak_bytes"] = memory_peak_bytes()
-    weights, images = server.weights, server.images
+    net, weights, images = server.net, server.weights, server.images
     release(server)
 
     reduced = None
@@ -600,11 +587,11 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
               f"{len(seg.waves)} waves, queue wait p95 "
               f"{1e3 * np.percentile(waits, 95) if waits else float('nan'):.1f}"
               " ms", file=err)
-    run = Run(cell, cfg, mix, peaks, setup_s, win, reduced)
+    run = Run(cell, cfg, mix, peaks, setup_s, win, reduced, net)
     kind = "per_layer" if trace else "end_to_end"
     metrics = read_metrics(run, metrics_for(bench, name, kind))
 
-    checked = check(cfg, weights, images, win)
+    checked = check(net, cfg, weights, images, win)
     checked["compiles_in_window"] = win.compiles
     line = limits_line(checked, cfg["rel_err_limit"])
     correct = is_correct(line, checked["compared"])
@@ -615,7 +602,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         result["breakdown"] = {"device_ops": reduced.top_ops(),
                                "idle_gaps": reduced.idle_gaps()}
         top = max(mix["buckets"])
-        for lay in work.net_work(cfg, top, mix["max_batch"], peaks) if peaks else ():
+        for lay in work.net_work(net, cfg, top, mix["max_batch"], peaks) if peaks else ():
             print(f"layer {lay['h']}px {lay['c_in']}->{lay['c_out']}: "
                   f"bound {lay['bound']} at bucket {top}, "
                   f"batch {mix['max_batch']}", file=err)

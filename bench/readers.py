@@ -91,7 +91,7 @@ def conv_roofline(run):
     least = conv = 0.0
     for w, m in pairs:
         least += sum(lay["least_s"] for lay in work.net_work(
-            run.cfg, w["bucket"], w["batch"], run.peaks))
+            run.net, run.cfg, w["bucket"], w["batch"], run.peaks))
         conv += tr.op_time(d, m.start, m.end, "conv")
     return 100.0 * least / conv if conv > 0 else None
 
@@ -115,7 +115,8 @@ def mfu(run):
     if run.peaks is None:
         return None
     win = run.window
-    flops = sum(work.image_flops(run.cfg, r["side"]) for r in done_in_window(run))
+    flops = sum(work.image_flops(run.net, run.cfg, r["side"])
+                for r in done_in_window(run))
     if not flops:
         return None
     return 100.0 * flops / ((win.t1 - win.t0) * run.peaks["flops_per_s"])

@@ -1,11 +1,11 @@
-"""The plain reference of a configuration, its weights, and its control.
+"""What every net's plain reference shares, and its control.
 
-Nothing here imports the program.  The reference is the net's forward
-pass in straightforward `jax.numpy`: XLA's direct convolution with
-"same" zero padding, a per-channel bias, ReLU, and 2x2 max-pooling, computed at `highest`
-matmul precision so that a float32 convolution on a TPU is a float32
-convolution.  The weights are made here, from the seed, and handed to
-both the program and the reference.
+Nothing here imports the program.  A configuration's reference is its
+net module's `forward` (`bench/nets/`) in straightforward `jax.numpy`;
+each of its products is `conv` here, XLA's direct convolution at
+`highest` matmul precision, so that a float32 convolution on a TPU is a
+float32 convolution.  The weights are made by the net module from the
+seed (`seed_key`), and handed to both the program and the reference.
 
 `precision` selects the arithmetic of the convolutions:
 
@@ -24,6 +24,7 @@ round trip as excess precision, and on a TPU it does.
 from __future__ import annotations
 
 import functools
+import json
 
 import jax
 import jax.numpy as jnp
@@ -40,37 +41,6 @@ def seed_key(seed: int) -> jax.Array:
 BIAS_STD = 0.1
 
 
-def weight_shapes(layers: list) -> dict:
-    """Shape of every layer's weights, keyed by layer index: HWIO for a
-    conv's kernel, (C,) for a bias vector."""
-    shapes = {}
-    for i, lay in enumerate(layers):
-        if lay["kind"] == "conv":
-            shapes[i] = (lay.get("k", 3), lay.get("k", 3),
-                         lay["c_in"] // lay.get("groups", 1), lay["c_out"])
-        elif lay["kind"] == "bias":
-            shapes[i] = (lay["c"],)
-    return shapes
-
-
-def make_weights(layers: list, seed: int) -> dict:
-    """He-normal HWIO kernels and normal bias vectors (std BIAS_STD)
-    from the seed, made on the device in one jitted call, in float32."""
-    shapes = weight_shapes(layers)
-
-    @jax.jit
-    def init(key):
-        out = {}
-        for i, shp in shapes.items():
-            std = (BIAS_STD if len(shp) == 1
-                   else (2.0 / (shp[0] * shp[1] * shp[2])) ** 0.5)
-            out[i] = std * jax.random.normal(
-                jax.random.fold_in(key, i), shp, jnp.float32)
-        return out
-
-    return init(seed_key(seed))
-
-
 def _round_bf16(a):
     """float32 rounded to the nearest bfloat16 (ties to even), kept in
     float32: the low 16 bits of the word cleared after rounding."""
@@ -85,7 +55,10 @@ def _split(a):
     return hi, _round_bf16(a - hi)
 
 
-def _conv(x, w, lay, precision):
+def conv(x, w, lay: dict, precision: str):
+    """An NHWC by HWIO convolution with zero padding, at `precision`.
+    `lay` gives its geometry: k (3), pad (k // 2), stride (1) and
+    groups (1)."""
     k = lay.get("k", 3)
     pad = lay.get("pad", k // 2)
     s = lay.get("stride", 1)
@@ -109,38 +82,26 @@ def _conv(x, w, lay, precision):
     raise ValueError(f"unknown precision {precision!r}")
 
 
-def forward(layers: list, weights: dict, x, precision: str = "highest"):
-    """The net on an NHWC batch of equal-sized images."""
-    for i, lay in enumerate(layers):
-        kind = lay["kind"]
-        if kind == "conv":
-            x = _conv(x, weights[i], lay, precision)
-        elif kind == "bias":
-            x = x + weights[i]
-        elif kind == "relu":
-            x = jnp.maximum(x, 0.0)
-        elif kind == "maxpool":
-            b, h, w, c = x.shape
-            v = lay.get("window", 2)
-            x = x.reshape(b, h // v, v, w // v, v, c).max(axis=(2, 4))
-        else:
-            raise ValueError(f"layer {i}: unknown kind {kind!r}")
-    return x
-
-
 @functools.lru_cache(maxsize=None)
-def jitted(precision: str):
-    """`forward` jitted, with the layers (frozen) and precision static."""
-    return jax.jit(forward, static_argnums=(0, 3))
+def _jitted(net, cfg_json: str, precision: str):
+    cfg = json.loads(cfg_json)
+    return jax.jit(lambda weights, x: net.forward(cfg, weights, x, precision))
 
 
-def run(layers: list, weights: dict, images: list, precision: str = "highest",
-        block: int = 8) -> list:
+def jitted(net, cfg: dict, precision: str):
+    """The net module's `forward` jitted with the configuration and the
+    precision closed over: one compiled function per configuration and
+    precision, called as `fn(weights, x)`.  A configuration holds
+    nested dicts, so it is keyed by its JSON text."""
+    return _jitted(net, json.dumps(cfg, sort_keys=True), precision)
+
+
+def run(net, cfg: dict, weights: dict, images: list,
+        precision: str = "highest", block: int = 8) -> list:
     """The reference's output for each image (a list of HWC arrays, any
     sizes), computed in blocks of `block` equal-sized images; a short
     block is padded with zero images so that each size compiles once."""
-    key = freeze(layers)
-    fn = jitted(precision)
+    fn = jitted(net, cfg, precision)
     out = [None] * len(images)
     by_side = {}
     for j, im in enumerate(images):
@@ -151,26 +112,10 @@ def run(layers: list, weights: dict, images: list, precision: str = "highest",
             x = np.zeros((block,) + shape, np.float32)
             for r, j in enumerate(part):
                 x[r] = images[j]
-            y = np.asarray(fn(key, weights, jnp.asarray(x), precision))
+            y = np.asarray(fn(weights, jnp.asarray(x)))
             for r, j in enumerate(part):
                 out[j] = y[r]
     return out
-
-
-class _Frozen(tuple):
-    """Layers as a hashable static argument that still reads as a list
-    of dicts."""
-
-    def __new__(cls, layers):
-        return super().__new__(cls, tuple(tuple(sorted(d.items()))
-                                          for d in layers))
-
-    def __iter__(self):
-        return (dict(items) for items in tuple.__iter__(self))
-
-
-def freeze(layers: list) -> _Frozen:
-    return _Frozen(layers)
 
 
 def rel_err(y: np.ndarray, ref: np.ndarray) -> float:

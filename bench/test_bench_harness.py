@@ -18,12 +18,17 @@ import time
 import numpy as np
 import pytest
 
-from bench import control, harness, readers, reference, traffic, work
+from bench import control, harness, nets, readers, reference, traffic, work
 
 DATA = pathlib.Path(__file__).resolve().parent / "testdata"
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 PEAKS = {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 SEED = 2 ** 40 + 3  # more than 32 bits, as the driver's seeds are
+
+
+# the closed-loop rehearsals: the chain, and a net brought by its own
+# module (bench/testdata/stages.py)
+BATCH = ["tiny.closed", "stages.closed"]
 
 
 def _bench():
@@ -37,11 +42,13 @@ def _bench():
              "traffic": "tiny-closed", "chips": 1, "why": "rehearsal"},
             {"name": "tiny.open", "config": "tiny",
              "traffic": "tiny-open", "chips": 1, "why": "rehearsal"},
+            {"name": "stages.closed", "config": "stages",
+             "traffic": "tiny-closed", "chips": 1, "why": "rehearsal"},
         ],
         "end_to_end": [
             {"name": "setup_s", "unit": "s", "better": "lower",
              "bound": 0.25, "source": "host_clock"},
-            m("images_per_s", "images/s", ["tiny.closed"]),
+            m("images_per_s", "images/s", ["tiny.closed", "stages.closed"]),
             m("p50_ms", "ms", ["tiny.open"]),
             m("p95_ms", "ms", ["tiny.open"]),
         ],
@@ -52,9 +59,9 @@ def _bench():
             m("device_ms_per_wave.online", "ms", ["tiny.open"]),
             m("device_idle.online", "%", ["tiny.open"]),
             m("mfu.online", "%", ["tiny.open"]),
-            m("device_ms_per_wave.batch", "ms", ["tiny.closed"]),
-            m("device_idle.batch", "%", ["tiny.closed"]),
-            m("mfu.batch", "%", ["tiny.closed"]),
+            m("device_ms_per_wave.batch", "ms", BATCH),
+            m("device_idle.batch", "%", BATCH),
+            m("mfu.batch", "%", BATCH),
         ],
     }
 
@@ -65,6 +72,7 @@ def _run(cell, seconds=0.6, trace=False, tmp_path=None):
         cell, SEED, seconds, trace, t_start=time.monotonic(), bench=_bench(),
         require_chip=False, peaks=PEAKS, out=out, err=err,
         config_dir=DATA, traffic_dir=DATA,
+        net_dir=DATA if cell.startswith("stages") else nets.NET_DIR,
         trace_dir=str(tmp_path / "trace") if trace else None)
     last = out.getvalue().strip().splitlines()[-1]
     assert json.loads(last) == result
@@ -114,12 +122,13 @@ def test_open_loop_exact_percentiles_and_no_compiles():
                                  / sum(w["batch"] for w in waves))
     # partial waves ride the warmed max_batch program, padded
     assert {w["batch"] for w in win.waves} == {mix["max_batch"]}
-    checked = harness.check(cfg, server.weights, server.images, win)
+    checked = harness.check(server.net, cfg, server.weights, server.images,
+                            win)
     assert checked["missing_answers"] == 0
     assert checked["worst_rel_err"] < cfg["rel_err_limit"]
 
 
-@pytest.mark.parametrize("cell", ["tiny.open", "tiny.closed"])
+@pytest.mark.parametrize("cell", ["tiny.open", "tiny.closed", "stages.closed"])
 def test_traced_line_covers_the_windows_tail(tmp_path, monkeypatch, cell):
     """A traced run serves its window untraced, then a segment of
     TRACE_S seconds under the profiler; `window_s` is that segment."""
@@ -134,7 +143,7 @@ def test_traced_line_covers_the_windows_tail(tmp_path, monkeypatch, cell):
     bd = result["breakdown"]
     assert 0 < len(bd["device_ops"]) <= 10 and 0 < len(bd["idle_gaps"]) <= 10
     assert "setup_s" not in result["metrics"]
-    kind = "online" if cell == "tiny.open" else "batch"
+    kind = "online" if cell.endswith(".open") else "batch"
     # `.batch` and `.online` are read by one file each, found by name
     assert {f"device_ms_per_wave.{kind}", f"device_idle.{kind}",
             f"mfu.{kind}"} <= set(result["metrics"])
@@ -161,7 +170,7 @@ def test_host_readings_come_from_the_untraced_window(tmp_path):
     assert len(seg.requests) == round(0.5 * mix["rate_hz"] * harness.TRACE_S)
     assert not {r["rid"] for r in win.requests} & {r["rid"] for r in seg.requests}
     tr = trace_reduce.reduce_file(trace_reduce.find_xplane(str(tmp_path)))
-    run = harness.Run({}, cfg, mix, PEAKS, 1.0, win, tr)
+    run = harness.Run({}, cfg, mix, PEAKS, 1.0, win, tr, server.net)
     assert readers.due_in_window(run) == win.requests
     waits = [r["dispatch"] - r["admit"] for r in win.requests]
     assert harness.load_reader("queue_wait_p95_ms.online")(run) == \
@@ -172,7 +181,8 @@ def test_host_readings_come_from_the_untraced_window(tmp_path):
                for w in readers.waves_in_window(run))
     assert harness.load_reader("device_idle.online")(run) == \
         pytest.approx(100 * (1 - busy / (win.t1 - win.t0)))
-    flops = sum(work.image_flops(cfg, r["side"]) for r in win.requests
+    flops = sum(work.image_flops(server.net, cfg, r["side"])
+                for r in win.requests
                 if win.t0 <= r["done"] <= win.t1)
     assert harness.load_reader("mfu.online")(run) == pytest.approx(
         100 * flops / ((win.t1 - win.t0) * PEAKS["flops_per_s"]))
@@ -187,9 +197,10 @@ def test_seeds_order_the_same_work():
     assert all(0 <= x.t < 5.0 for x in a)
     # the same seed gives the same traffic and the same weights
     assert a == traffic.open_arrivals(mix, 5.0, 1)
-    layers = harness.load_config("tiny", DATA)["layers"]
-    w1 = reference.make_weights(layers, 2 ** 33 + 1)
-    w2 = reference.make_weights(layers, 1)
+    cfg = harness.load_config("tiny", DATA)
+    net = nets.load(cfg)
+    w1 = net.make_weights(cfg, 2 ** 33 + 1)
+    w2 = net.make_weights(cfg, 1)
     assert not np.allclose(np.asarray(w1[0]), np.asarray(w2[0]))
 
 
@@ -236,11 +247,11 @@ def test_reference_in_the_programs_place(monkeypatch, precision, correct):
     the wave program fails the check; the reference itself passes it."""
     from repro.convserve import executor
 
-    layers = reference.freeze(harness.load_config("tiny", DATA)["layers"])
-    fn = reference.jitted(precision)
+    cfg = harness.load_config("tiny", DATA)
+    fn = reference.jitted(nets.load(cfg), cfg, precision)
 
     def call(self, x, sizes=None, *, mesh=None):
-        return fn(layers, self.weights, np.asarray(x, np.float32), precision)
+        return fn(self.weights, np.asarray(x, np.float32))
 
     monkeypatch.setattr(executor.NetExecutor, "__call__", call)
     result, _ = _run("tiny.closed", seconds=0.4)
@@ -269,9 +280,10 @@ def test_control_served_through_the_check(precision, correct):
     server = harness.prepare(cfg, mix, SEED)
     win = harness.drive(server, 0.4, SEED)
     harness.release(server)
-    ctl = control._control(cfg, server.weights, server.images, win, precision)
+    ctl = control._control(server.net, cfg, server.weights, server.images,
+                           win, precision)
     assert set(ctl.results) == set(win.results)
-    got = harness.check(cfg, server.weights, server.images, ctl)
+    got = harness.check(server.net, cfg, server.weights, server.images, ctl)
     got["compiles_in_window"] = win.compiles
     line = harness.limits_line(got, cfg["rel_err_limit"])
     assert harness.is_correct(line, got["compared"]) is correct
@@ -286,15 +298,15 @@ def test_reference_biases_match_the_programs_semantics():
     from repro.convserve.graph import run_direct
 
     cfg = harness.load_config("tiny", DATA)
-    layers = cfg["layers"]
-    assert sum(lay["kind"] == "bias" for lay in layers) == 4
-    w = reference.make_weights(layers, SEED)
+    net = nets.load(cfg)
+    assert sum(lay["kind"] == "bias" for lay in cfg["layers"]) == 4
+    w = net.make_weights(cfg, SEED)
     x = jnp.asarray(np.random.default_rng(0).standard_normal((2, 16, 16, 3)),
                     jnp.float32)
-    ref = np.asarray(reference.forward(layers, w, x))
+    ref = np.asarray(net.forward(cfg, w, x))
     with jax.default_matmul_precision("highest"):
-        got = np.asarray(run_direct(harness.netspec(cfg), dict(w), x))
+        got = np.asarray(run_direct(net.netspec(cfg), dict(w), x))
     assert reference.rel_err(got, ref) < 1e-5
     no_bias = {i: (a * 0 if a.ndim == 1 else a) for i, a in w.items()}
-    dropped = np.asarray(reference.forward(layers, no_bias, x))
+    dropped = np.asarray(net.forward(cfg, no_bias, x))
     assert reference.rel_err(dropped, ref) > 1e-3

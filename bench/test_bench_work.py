@@ -2,14 +2,14 @@
 
 import pytest
 
-from bench import harness, work
+from bench import harness, nets, work
 
 V5E = "TPU v5 lite"
 
 
 def _sum(cfg_name, side):
-    rows = work.net_work(harness.load_config(cfg_name), side, 1,
-                         work.load_peaks(V5E))
+    cfg = harness.load_config(cfg_name)
+    rows = work.net_work(nets.load(cfg), cfg, side, 1, work.load_peaks(V5E))
     return rows, sum(r["flops"] for r in rows), sum(r["least_s"] for r in rows)
 
 
@@ -41,8 +41,9 @@ def test_vgg16_s3_at_512_by_hand():
 
 def test_batch_counts_weights_once():
     cfg = harness.load_config("vgg13-s3")
-    one = work.net_work(cfg, 224, 1, work.load_peaks(V5E))
-    eight = work.net_work(cfg, 224, 8, work.load_peaks(V5E))
+    net = nets.load(cfg)
+    one = work.net_work(net, cfg, 224, 1, work.load_peaks(V5E))
+    eight = work.net_work(net, cfg, 224, 8, work.load_peaks(V5E))
     for a, b in zip(one, eight):
         w = 4 * 9 * a["c_in"] * a["c_out"]
         assert b["flops"] == 8 * a["flops"]
@@ -51,7 +52,8 @@ def test_batch_counts_weights_once():
 
 def test_image_flops_shrinks_with_the_side():
     cfg = harness.load_config("vgg13-s3")
-    assert work.image_flops(cfg, 112) * 4 == work.image_flops(cfg, 224)
+    net = nets.load(cfg)
+    assert work.image_flops(net, cfg, 112) * 4 == work.image_flops(net, cfg, 224)
 
 
 def test_unknown_device_kind_raises():

@@ -34,33 +34,6 @@ def load_peaks(device_kind: str) -> dict:
         ) from None
 
 
-def conv_shapes(layers: list, side: int) -> list:
-    """Each conv layer's geometry at a square input of `side` pixels:
-    dicts with h, w (input), ho, wo (output), c_in, c_out, k, groups."""
-    out = []
-    h = w = side
-    for lay in layers:
-        kind = lay["kind"]
-        if kind == "conv":
-            k = lay.get("k", 3)
-            pad = lay.get("pad", k // 2)
-            s = lay.get("stride", 1)
-            ho = (h + 2 * pad - k) // s + 1
-            wo = (w + 2 * pad - k) // s + 1
-            out.append({
-                "h": h, "w": w, "ho": ho, "wo": wo, "c_in": lay["c_in"],
-                "c_out": lay["c_out"], "k": k,
-                "groups": lay.get("groups", 1),
-            })
-            h, w = ho, wo
-        elif kind == "maxpool":
-            win = lay.get("window", 2)
-            h, w = h // win, w // win
-        elif kind not in ("bias", "relu"):
-            raise ValueError(f"unknown layer kind {kind!r}")
-    return out
-
-
 def layer_work(g: dict, batch: int, dtype_bytes: int) -> tuple:
     """(operations, least bytes) of one conv layer over `batch` images."""
     flops = (2 * g["ho"] * g["wo"] * g["c_in"] * g["c_out"] * g["k"] ** 2
@@ -78,12 +51,13 @@ def least_time(flops: float, nbytes: float, peaks: dict) -> tuple:
     return (t_c, "compute") if t_c >= t_m else (t_m, "hbm")
 
 
-def net_work(cfg: dict, side: int, batch: int, peaks: dict) -> list:
-    """Per conv layer of a configuration at `side` px and `batch` images:
-    dicts with the geometry, flops, bytes, least_s and bound."""
+def net_work(net, cfg: dict, side: int, batch: int, peaks: dict) -> list:
+    """Per conv layer of a configuration at `side` px and `batch` images,
+    as its net module (`bench/nets/`) lists them: dicts with the
+    geometry, flops, bytes, least_s and bound."""
     nb = DTYPE_BYTES[cfg["dtype"]]
     rows = []
-    for g in conv_shapes(cfg["layers"], side):
+    for g in net.conv_shapes(cfg, side):
         flops, nbytes = layer_work(g, batch, nb)
         t, bound = least_time(flops, nbytes, peaks)
         rows.append({**g, "flops": flops, "bytes": nbytes, "least_s": t,
@@ -91,9 +65,9 @@ def net_work(cfg: dict, side: int, batch: int, peaks: dict) -> list:
     return rows
 
 
-def image_flops(cfg: dict, side: int) -> int:
+def image_flops(net, cfg: dict, side: int) -> int:
     """Direct-convolution operations of one image of `side` pixels."""
-    return sum(r["flops"] for r in net_work(cfg, side, 1, _NO_PEAKS))
+    return sum(r["flops"] for r in net_work(net, cfg, side, 1, _NO_PEAKS))
 
 
 _NO_PEAKS = {"flops_per_s": 1.0, "hbm_bytes_per_s": 1.0}
